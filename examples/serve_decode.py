@@ -15,12 +15,14 @@ sys.path.insert(0, "src")
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.registry import get_config
 from repro.launch.steps import make_serve_step
 from repro.models.model import init_cache, init_model
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--batch", type=int, default=4)

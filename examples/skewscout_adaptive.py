@@ -10,6 +10,7 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.base import CommConfig
 from repro.configs.cnn_zoo import CNN_ZOO
 from repro.core import partition_label_skew, train_decentralized
@@ -17,6 +18,7 @@ from repro.data.synthetic import synth_images
 
 
 def main():
+    use_compile_cache()
     ds = synth_images(3000, seed=0, noise=0.8, class_sep=0.35)
     val = synth_images(800, seed=99, noise=0.8, class_sep=0.35)
     cfg = CNN_ZOO["gn-lenet"]

@@ -1,22 +1,22 @@
 """End-to-end driver: decentralized training of a transformer LM with the
 production step functions (the same code path the dry-run lowers for the
-512-chip mesh), on CPU with a reduced model.
+512-chip mesh), with a reduced model.
 
-Two pods x (data, model) mesh on 8 fake host devices; the configured
-strategy controls the cross-pod exchange — Gaia's masked psum, or the
-D-PSGD/AD-PSGD gossip ring over a topology fabric (per-round neighbor
-operands, so a rotating schedule reuses one compilation).  Trains a
-~10M-param qwen3-family model on synthetic Markov token streams for a
-few hundred steps and reports the loss curve and cross-pod communication.
+The mesh is built from the devices JAX finds: one site (mesh ``pod``)
+per device.
+The configured strategy controls the cross-pod exchange — Gaia's masked
+psum, or the D-PSGD/AD-PSGD gossip ring over a topology fabric
+(per-round neighbor operands, so a rotating schedule reuses one
+compilation).  Trains a ~10M-param qwen3-family model on synthetic
+Markov token streams for a few hundred steps and reports the loss curve.
 
   PYTHONPATH=src python examples/train_lm_decentralized.py \
       [--steps 200] [--strategy gaia|dpsgd|adpsgd] [--topology ring] \
       [--d-model 256] [--layers 4]
+
+On the CPU, give JAX one host device per site first, e.g.
+``XLA_FLAGS=--xla_force_host_platform_device_count=2``.
 """
-import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import argparse
 import dataclasses
 import sys
@@ -28,9 +28,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.base import CommConfig, FabricConfig
 from repro.configs.registry import get_config
 from repro.data.synthetic import synth_tokens
+from repro.launch.mesh import make_mesh
 from repro.launch.sharding import batch_shardings, train_state_shardings
 from repro.launch.steps import (GOSSIP_STRATEGIES, gossip_operands,
                                 make_train_state, make_train_step)
@@ -41,13 +43,14 @@ from repro.topology.graphs import build_demo_schedule
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--strategy", default="gaia",
                     choices=["bsp", "gaia", "fedavg", "dgc",
                              "dpsgd", "adpsgd"])
     ap.add_argument("--topology", default="ring",
-                    help="gossip fabric across the two pods")
+                    help="gossip fabric across the pods")
     ap.add_argument("--staleness", type=int, default=1,
                     help="adpsgd staleness rung (<= max_staleness=2)")
     ap.add_argument("--d-model", type=int, default=256)
@@ -69,19 +72,25 @@ def main():
     print(f"arch=qwen3-family reduced  params~{n_params/1e6:.1f}M  "
           f"strategy={args.strategy}")
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    pods = len(jax.devices())
+    if pods < 2:
+        raise SystemExit(
+            "one site per device needs >= 2 devices (on the CPU set "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=2)")
+    mesh = make_mesh((pods, 1, 1), ("pod", "data", "model"))
+    print(f"{pods} sites on {jax.devices()[0].platform}")
     comm = CommConfig(strategy=args.strategy,
                       fabric=FabricConfig(topology=args.topology),
                       gaia_t0=0.05, iter_local=10, dgc_sparsity=0.95)
     params = init_model(jax.random.PRNGKey(0), cfg)
-    state = make_train_state(params, comm, 2)
+    state = make_train_state(params, comm, pods)
 
     data = synth_tokens(512, args.seq + 1, vocab=cfg.vocab, seed=0)
     rng = np.random.default_rng(0)
 
     def next_batch():
         idx = rng.integers(0, data.tokens.shape[0],
-                           size=(2, args.batch_per_pod))
+                           size=(pods, args.batch_per_pod))
         seqs = data.tokens[idx]
         return {"tokens": jnp.asarray(seqs[..., :-1]),
                 "labels": jnp.asarray(seqs[..., 1:])}
@@ -89,7 +98,7 @@ def main():
     gossip = args.strategy in GOSSIP_STRATEGIES
     # label-aware fabrics get the synthetic full-skew histogram (the
     # Markov stream has no labels to derive one from)
-    sched = build_demo_schedule(args.topology, 2) if gossip else None
+    sched = build_demo_schedule(args.topology, pods) if gossip else None
     with mesh, activation_sharding(mesh):
         s_shard = train_state_shardings(jax.eval_shape(lambda: state), mesh)
         b_shard = batch_shardings(jax.eval_shape(next_batch), mesh,

@@ -18,6 +18,7 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.base import CommConfig, FabricConfig
 from repro.configs.cnn_zoo import CNN_ZOO
 from repro.core.partition import partition_label_skew
@@ -27,6 +28,7 @@ from repro.topology import LINK_PROFILES, build_topology
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--skew", type=float, default=1.0)

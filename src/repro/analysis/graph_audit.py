@@ -272,7 +272,7 @@ def audit_hlo(text: str, *, tag: str = "<hlo>",
                 dt = _first_dtype(ins.type_str)
                 if dt is None:
                     continue
-                b = m * hlo._shape_bytes(ins.type_str)
+                b = m * hlo._collective_bytes(ins)
                 rep.cross_pod_dtype_bytes[dt] = \
                     rep.cross_pod_dtype_bytes.get(dt, 0.0) + b
                 if dt in _FLOAT_BYTES and _FLOAT_BYTES[dt] > exp_b:
@@ -280,7 +280,7 @@ def audit_hlo(text: str, *, tag: str = "<hlo>",
                          f"cross-pod transfer `{ins.name}` ships {dt} "
                          f"but the leaf dtype is {expected} — the "
                          "payload widened on the wire "
-                         f"({hlo._shape_bytes(ins.type_str)} bytes/step)",
+                         f"({hlo._collective_bytes(ins)} bytes/step)",
                          ins.name)
 
     # ---- host callbacks (GA203) ----

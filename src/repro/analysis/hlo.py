@@ -63,6 +63,31 @@ def _shape_bytes(type_str: str) -> int:
     return total
 
 
+def _tuple_elements(type_str: str) -> List[str]:
+    """Top-level element types of a tuple type string."""
+    out, depth, start = [], 0, 1
+    for i, ch in enumerate(type_str):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+        if (ch == "," and depth == 1) or depth == 0:
+            out.append(type_str[start:i].strip())
+            start = i + 1
+        if depth == 0:
+            break
+    return out
+
+
+def _collective_bytes(ins: "Instr") -> int:
+    """Bytes one collective moves.  An async ``collective-permute-start``
+    is typed ``(operand, result, u32[], u32[])`` on TPU (``(operand,
+    result)`` elsewhere): only its operand goes over the wire."""
+    if ins.op == "collective-permute-start" and ins.type_str.startswith("("):
+        return _shape_bytes(_tuple_elements(ins.type_str)[0])
+    return _shape_bytes(ins.type_str)
+
+
 def _shape_dims(type_str: str) -> List[List[int]]:
     """All array shapes in a (possibly tuple) type string."""
     out = []
@@ -434,7 +459,7 @@ def pod_exchange_report(text: str, devices_per_pod: int) -> PodExchange:
             if ins.op.endswith("-done"):
                 continue                 # bytes counted at the -start
             base = ins.op[:-6] if ins.op.endswith("-start") else ins.op
-            b = m * _shape_bytes(ins.type_str)
+            b = m * _collective_bytes(ins)
             if base == "collective-permute":
                 pairs = _parse_pairs(ins.rest)
                 if pairs is None:
@@ -509,7 +534,7 @@ def analyze(text: str) -> HLOCost:
             # ---- collectives ----
             for kind in COLLECTIVES:
                 if ins.op == kind or ins.op == kind + "-start":
-                    b = m * _shape_bytes(ins.type_str)
+                    b = m * _collective_bytes(ins)
                     cost.collective_bytes[kind] += b
                     bucket = f"{kind}:{_opname_bucket(ins.rest)}"
                     cost.coll_by_op[bucket] = (

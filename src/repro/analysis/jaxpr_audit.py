@@ -46,6 +46,7 @@ without jax and tests can feed it hand-built traces.  Only
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
@@ -101,12 +102,15 @@ def _is_jaxpr(x: Any) -> bool:
 
 
 def _as_open(x: Any) -> Optional[Tuple[Any, List[Any]]]:
-    """(open jaxpr, consts) for a Jaxpr or ClosedJaxpr, else None."""
-    if _is_jaxpr(x):
-        return x, []
+    """(open jaxpr, consts) for a Jaxpr or ClosedJaxpr, else None.  The
+    ClosedJaxpr test comes first: jax 0.9's ClosedJaxpr also exposes
+    ``eqns`` and ``invars``, and taking it for an open jaxpr would drop
+    its consts."""
     inner = getattr(x, "jaxpr", None)
     if inner is not None and _is_jaxpr(inner):
         return inner, list(getattr(x, "consts", []))
+    if _is_jaxpr(x):
+        return x, []
     return None
 
 
@@ -380,10 +384,14 @@ def audit_jaxpr(closed_jaxpr, *, tag: str = "<jaxpr>",
 
     # ---- JA404: large closed-over constants ----
     for scope, c in g.consts:
-        nb = int(getattr(c, "nbytes", 0) or 0)
+        shape = list(getattr(c, "shape", ()))
+        # jax 0.9 closes numpy constants over as TypedNdArray, which has
+        # shape and dtype but no nbytes
+        nb = int(getattr(c, "nbytes", None)
+                 or math.prod(shape)
+                 * getattr(getattr(c, "dtype", None), "itemsize", 0))
         rep.max_const_bytes = max(rep.max_const_bytes, nb)
         if nb > const_threshold_bytes:
-            shape = list(getattr(c, "shape", ()))
             dt = getattr(getattr(c, "dtype", None), "name", "?")
             emit("JA404",
                  f"{nb} -byte constant ({dt}{shape}) closed over into "

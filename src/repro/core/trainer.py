@@ -6,6 +6,7 @@ communication, and report validation accuracy of the global model.
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -281,6 +282,9 @@ def train_decentralized(cnn_cfg: CNNConfig, algo_name: str,
                           ledger=ledger, participation=part)
 
     loss_curve, acc_curve, gap_curve, stale_curve = [], [], [], []
+    # host wall time of each step, ended by block_until_ready; step 0
+    # includes tracing and compiling the step
+    step_s: List[float] = []
     comm_total = 0.0
     steps_per_epoch = loader.steps_per_epoch
 
@@ -300,8 +304,11 @@ def train_decentralized(cnn_cfg: CNNConfig, algo_name: str,
             s = (scout.theta if scout
                  else warmup_sparsity(epoch, comm.dgc_warmup_epochs))
             kw["sparsity"] = jnp.asarray(s, jnp.float32)
+        t_step = time.perf_counter()
         state, metrics = algo.step(state, sbatch, lr_t,
                                    jnp.asarray(t, jnp.int32), **kw)
+        jax.block_until_ready(state)
+        step_s.append(time.perf_counter() - t_step)
         cf = float(metrics["comm_floats"])
         comm_total += cf
         if algo_name in GOSSIP_ALGOS:
@@ -348,6 +355,11 @@ def train_decentralized(cnn_cfg: CNNConfig, algo_name: str,
         raise RuntimeError(
             f"no evaluation happened in {steps} steps (eval_every="
             f"{eval_every}); acc_curve is empty — check the schedule")
+    # Mosaic kernel calls in the step as lowered for this backend (0
+    # where Pallas kernels run in interpret mode): the last step's own
+    # operands, with its round index static as the gossip steps need
+    mosaic_calls = jax.jit(algo.step, static_argnums=3).lower(
+        state, sbatch, lr_t, t, **kw).as_text().count("tpu_custom_call")
     bsp_equiv = float(tree_size(params)) * steps
     # the fabric the run *ended* on (rung switches may have moved it)
     final_sched = as_schedule(algo.schedule) \
@@ -363,6 +375,8 @@ def train_decentralized(cnn_cfg: CNNConfig, algo_name: str,
         comm_savings=bsp_equiv / max(comm_total, 1.0),
         skewscout_history=list(scout.history) if scout else [],
         extras={"ledger": ledger.summary(),
+                "step_s": step_s,
+                "mosaic_calls": mosaic_calls,
                 "spectral_gap": final_sched.spectral_gap(),
                 "spectral_gap_curve": gap_curve,
                 "schedule_period": final_sched.period,

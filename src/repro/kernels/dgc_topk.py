@@ -36,6 +36,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import rng
+from repro.kernels.gaia_select import count_spec, partial_counts
 
 LANES = 128
 N_BINS = 256
@@ -162,7 +163,7 @@ def _randk_kernel(v_ref, seed_ref, p_ref, out_ref, cnt_ref, *, n: int):
     u = rng.uniform01(seed_ref[0].astype(jnp.uint32), idx)
     keep = (u < p_ref[0]) & (idx < n)          # padding never selects
     out_ref[...] = jnp.where(keep, v, jnp.zeros_like(v))
-    cnt_ref[0, 0] = jnp.sum(keep.astype(jnp.int32))
+    cnt_ref[...] = partial_counts(keep)
 
 
 def rand_k_select(v: jnp.ndarray, keep_prob: jnp.ndarray,
@@ -176,22 +177,20 @@ def rand_k_select(v: jnp.ndarray, keep_prob: jnp.ndarray,
     v2, n, n_blocks = _blocked(v, block_rows)
     seed_arr = jnp.asarray(seed, jnp.int32).reshape(1)
     p_arr = jnp.asarray(keep_prob, jnp.float32).reshape(1)
+    cnt_block, cnt_shape = count_spec(block_rows, n_blocks)
     out, cnt = pl.pallas_call(
         functools.partial(_randk_kernel, n=n),
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),       # seed scalar
-            pl.BlockSpec(memory_space=pl.ANY),       # keep_prob scalar
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # seed scalar
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # keep_prob scalar
         ],
         out_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            cnt_block,
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct(v2.shape, v.dtype),
-            jax.ShapeDtypeStruct((n_blocks, 1), jnp.int32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct(v2.shape, v.dtype), cnt_shape],
         interpret=interpret,
     )(v2, seed_arr, p_arr)
     return out.reshape(-1)[:n].reshape(orig_shape), jnp.sum(cnt)
@@ -202,7 +201,7 @@ def _select_kernel(v_ref, t_ref, out_ref, cnt_ref):
     t = t_ref[0]
     mask = jnp.abs(v.astype(jnp.float32)) > t
     out_ref[...] = jnp.where(mask, v, jnp.zeros_like(v))
-    cnt_ref[0, 0] = jnp.sum(mask.astype(jnp.int32))
+    cnt_ref[...] = partial_counts(mask)
 
 
 def dgc_select(v: jnp.ndarray, threshold: jnp.ndarray, *,
@@ -211,22 +210,20 @@ def dgc_select(v: jnp.ndarray, threshold: jnp.ndarray, *,
     orig_shape = v.shape
     v2, n, n_blocks = _blocked(v, block_rows)
     t_arr = jnp.asarray(threshold, jnp.float32).reshape(1)
+    cnt_block, cnt_shape = count_spec(block_rows, n_blocks)
 
     out, cnt = pl.pallas_call(
         _select_kernel,
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            cnt_block,
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct(v2.shape, v.dtype),
-            jax.ShapeDtypeStruct((n_blocks, 1), jnp.int32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct(v2.shape, v.dtype), cnt_shape],
         interpret=interpret,
     )(v2, t_arr)
     return out.reshape(-1)[:n].reshape(orig_shape), jnp.sum(cnt)

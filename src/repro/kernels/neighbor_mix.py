@@ -16,8 +16,8 @@ padding rows contribute ``0 * x[k]`` and no branching is needed.
 
 ``nbr_idx``/``nbr_w`` are *runtime operands*, not trace-time constants:
 only their (K, D) shape is baked into the compiled kernel (the k/d loops
-unroll over it), while the index values are gathered with
-``dynamic_index_in_dim`` at run time.  A :class:`TopologySchedule` that
+unroll over it), while the index values sit in SMEM and pick the
+neighbor's row of the VMEM block at run time.  A :class:`TopologySchedule` that
 changes the neighbor set every round therefore reuses one compilation,
 provided every round pads to the schedule-wide max degree
 (``TopologySchedule.neighbor_arrays`` does) — that compile-once contract
@@ -41,33 +41,26 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 
 
 def _mix_kernel(nbr_ref, w_ref, sw_ref, x_ref, out_ref):
-    x = x_ref[...].astype(jnp.float32)            # (K, block_rows, 128)
-    K, D = nbr_ref.shape
-    for k in range(K):                            # K, D static: unrolled
-        acc = sw_ref[k] * x[k]
-        for d in range(D):
-            xn = jax.lax.dynamic_index_in_dim(x, nbr_ref[k, d], axis=0,
-                                              keepdims=False)
-            acc = acc + w_ref[k, d] * xn
-        out_ref[k] = acc.astype(out_ref.dtype)
+    _mix_src_kernel(nbr_ref, w_ref, sw_ref, x_ref, x_ref, out_ref)
 
 
 def _mix_src_kernel(nbr_ref, w_ref, sw_ref, x_ref, src_ref, out_ref):
-    """Stale-mixing variant: neighbor rows gathered from ``src`` (M rows,
-    e.g. a stacked staleness-snapshot buffer), self term from ``x``."""
-    x = x_ref[...].astype(jnp.float32)            # (K, block_rows, 128)
-    src = src_ref[...].astype(jnp.float32)        # (M, block_rows, 128)
+    """out[k] = sw[k] * x[k] + sum_d w[k, d] * src[nbr[k, d]] over one
+    (rows, block_rows, 128) block.  Stale-mixing variant: neighbor rows
+    gathered from ``src`` (M rows, e.g. a stacked staleness-snapshot
+    buffer), self term from ``x``.  The neighbor row is picked by a
+    dynamic index on the ref's leading (untiled) axis, read from SMEM."""
     K, D = nbr_ref.shape
-    for k in range(K):
-        acc = sw_ref[k] * x[k]
+    for k in range(K):                            # K, D static: unrolled
+        acc = sw_ref[k] * x_ref[k].astype(jnp.float32)
         for d in range(D):
-            xn = jax.lax.dynamic_index_in_dim(src, nbr_ref[k, d], axis=0,
-                                              keepdims=False)
+            xn = src_ref[nbr_ref[k, d]].astype(jnp.float32)
             acc = acc + w_ref[k, d] * xn
         out_ref[k] = acc.astype(out_ref.dtype)
 
@@ -98,11 +91,8 @@ def neighbor_mix(x: jnp.ndarray, nbr_idx: jnp.ndarray, nbr_w: jnp.ndarray,
     n_blocks = rows_pad // block_rows
     block3 = lambda rows: pl.BlockSpec((rows, block_rows, LANES),
                                        lambda i: (0, i, 0))
-    scalars = [
-        pl.BlockSpec(memory_space=pl.ANY),        # nbr_idx (scalars)
-        pl.BlockSpec(memory_space=pl.ANY),        # nbr_w
-        pl.BlockSpec(memory_space=pl.ANY),        # self_w
-    ]
+    # nbr_idx, nbr_w, self_w: whole arrays in SMEM, read as scalars
+    scalars = [pl.BlockSpec(memory_space=pltpu.SMEM)] * 3
     operands = (jnp.asarray(nbr_idx, jnp.int32),
                 jnp.asarray(nbr_w, jnp.float32),
                 jnp.asarray(self_w, jnp.float32))

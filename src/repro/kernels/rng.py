@@ -110,7 +110,9 @@ def uniform01(key, ctr):
     numpy / jnp / in-kernel (top 24 bits of the hash, exact in f32)."""
     xp = _xp(key, ctr)
     bits = uniform_bits(key, ctr)
-    return (bits >> xp.uint32(8)).astype(xp.float32) * xp.float32(_INV24)
+    # the top 24 bits fit int32 exactly; Mosaic has no uint32 -> float cast
+    top = (bits >> xp.uint32(8)).astype(xp.int32)
+    return top.astype(xp.float32) * xp.float32(_INV24)
 
 
 def normal01(key, ctr, dtype=None):

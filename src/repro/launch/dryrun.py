@@ -26,7 +26,8 @@ from repro.analysis import hlo as hlo_analysis
 from repro.configs.base import CommConfig, FabricConfig, INPUT_SHAPES
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.launch import analysis
-from repro.launch.mesh import (devices_per_pod, make_production_mesh,
+from repro.launch.mesh import (devices_per_pod, make_mesh,
+                               make_production_mesh,
                                n_pods as mesh_n_pods)
 from repro.launch.sharding import (batch_shardings, cache_shardings,
                                    param_shardings,
@@ -91,7 +92,7 @@ def _parse_mesh(spec: Optional[str]):
             f"--mesh {spec!r}: expected 'pod,data,model' (3 dims) or "
             "'data,model' (2 dims)")
     axes = {3: ("pod", "data", "model"), 2: ("data", "model")}[len(dims)]
-    return jax.make_mesh(dims, axes)
+    return make_mesh(dims, axes)
 
 
 def build_step(arch: str, shape_name: str, *,

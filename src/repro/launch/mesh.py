@@ -12,12 +12,23 @@ device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``-typed — the one mesh
+    constructor of the repo.  The launch path leaves sharding of the
+    intermediates to GSPMD (with ``shard_hints`` as hints); jax's default
+    ``Explicit`` axes would instead demand an ``out_sharding`` at every
+    op that mixes sharded operands."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def mesh_axes(mesh) -> tuple:

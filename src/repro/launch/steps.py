@@ -47,8 +47,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import SHARD_MAP_CHECK_KW as _CHECK_KW
-from repro.compat import shard_map as _shard_map
 from repro.configs.base import CommConfig, ModelConfig
 from repro.models.model import (decode_step, forward, init_cache,
                                 init_model, loss_fn)
@@ -194,9 +192,9 @@ def _pod_mix_fn(strategy: str, mesh, n_pods: int, p_specs,
                 return y.astype(x.dtype)
             return tmap(mix_leaf, p)
 
-        return _shard_map(body, mesh=mesh,
-                          in_specs=(p_specs,) + op_specs,
-                          out_specs=p_specs, **{_CHECK_KW: False})
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(p_specs,) + op_specs,
+                             out_specs=p_specs, check_vma=False)
 
     def body(p, snaps, nbr_idx, nbr_w, self_w, stale):
         k = jax.lax.axis_index("pod")
@@ -236,11 +234,11 @@ def _pod_mix_fn(strategy: str, mesh, n_pods: int, p_specs,
             return y.astype(x.dtype)
         return tmap(mix_leaf, p, snaps)
 
-    return _shard_map(body, mesh=mesh,
-                      in_specs=(p_specs, snap_specs,
-                                P(None, None), P(None, None), P("pod"),
-                                P(None, None)),
-                      out_specs=p_specs, **{_CHECK_KW: False})
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(p_specs, snap_specs,
+                                   P(None, None), P(None, None), P("pod"),
+                                   P(None, None)),
+                         out_specs=p_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import SHARD_MAP_CHECK_KW as _CHECK_KW
-from repro.compat import shard_map as _shard_map
 from repro.configs.base import MoEConfig
 
 Params = Dict[str, Any]
@@ -132,13 +130,13 @@ def moe_apply_ep(p: Params, m: MoEConfig, x: jnp.ndarray, mesh
         y, aux = body(xb4.reshape(-1, d), rw, wg, wu, wd)
         return y.reshape(B_loc, 1, -1, d), aux
 
-    sm = _shard_map(
+    sm = jax.shard_map(
         body4, mesh=mesh,
         in_specs=(P("data", "model", None, None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=(P("data", "model", None, None), P()),
-        **{_CHECK_KW: False})
+        check_vma=False)
     x4 = x.reshape(B, M, T // M, d)
     y, aux = sm(x4, p["router"]["w"], wg, wu, wd)
     return y.reshape(B, T, d), aux

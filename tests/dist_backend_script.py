@@ -23,6 +23,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.configs.base import CommConfig
 from repro.configs.registry import get_config
+from repro.launch.mesh import make_mesh
 from repro.launch.sharding import (batch_shardings, cache_shardings,
                                    param_shardings, train_state_shardings)
 from repro.launch.steps import (gossip_operands, make_serve_step,
@@ -33,7 +34,7 @@ from repro.topology.graphs import ring
 
 
 def main():
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_config("qwen3-0.6b").reduced()
     key = jax.random.PRNGKey(0)
     params = init_model(key, cfg)

@@ -45,6 +45,7 @@ from repro.core.algorithms.dpsgd import DPSGD
 from repro.core.algorithms.fedavg import FedAvg
 from repro.core.algorithms.gaia import Gaia
 from repro.launch import hlo_analysis
+from repro.launch.mesh import make_mesh
 from repro.launch.sharding import batch_shardings, train_state_shardings
 from repro.launch.steps import (gossip_operands, make_train_state,
                                 make_train_step, train_state_shape)
@@ -80,7 +81,7 @@ def update_rel_errs(launch_p, core_p, p0):
 
 
 def main():
-    mesh = jax.make_mesh((K, 2, 1), ("pod", "data", "model"))
+    mesh = make_mesh((K, 2, 1), ("pod", "data", "model"))
     cfg = get_config("qwen3-0.6b").reduced()
     key = jax.random.PRNGKey(0)
     params = init_model(key, cfg)

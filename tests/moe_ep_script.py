@@ -6,10 +6,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import jax.numpy as jnp
 from repro.configs.base import MoEConfig
+from repro.launch.mesh import make_mesh
 from repro.models import moe as moe_mod
 from repro.models import moe_ep
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 m = MoEConfig(n_experts=4, n_shared=0, top_k=2, d_ff_expert=16,
               capacity_factor=16.0)   # generous: nothing drops either way
 d = 8

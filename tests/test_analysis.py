@@ -500,6 +500,18 @@ class TestJaxprAudit:
         # the same const under the default 1 MiB threshold is fine
         assert audit_jaxpr(jaxpr_of(step, aval(8, 64))).ok
 
+    def test_large_numpy_constant_is_ja404(self):
+        # a numpy array closed over as-is: the trace keeps it among the
+        # ClosedJaxpr's consts (as a TypedNdArray, which has no nbytes)
+        big = np.ones((64, 64), np.float32)
+
+        def step(x):
+            return x + big
+        a = audit_jaxpr(jaxpr_of(step, aval(64, 64)),
+                        const_threshold_bytes=1024)
+        assert [f.rule for f in a.findings] == ["JA404"]
+        assert a.max_const_bytes == big.nbytes
+
     def test_const_seed_rng_is_ja405_exactly_once(self):
         # PRNGKey(0) baked into the trace: the step replays the same
         # stream every call.  The whole seed->wrap->sample chain must

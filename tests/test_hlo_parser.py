@@ -185,6 +185,27 @@ class TestPodExchange:
         assert rep.unparsed == 1
         assert rep.reduce_cross_bytes == 128.0   # conservative bucket
 
+    @pytest.mark.parametrize("operand,want", [
+        ("bf16[32]{0}", 64.0),
+        ("(bf16[32]{0}, f32[4,2]{1,0})", 96.0),  # combined permute
+    ])
+    def test_async_permute_counts_operand_once(self, operand, want):
+        # TPU's async permute is typed (operand, result, u32[], u32[]):
+        # the wire carries the operand once, not operand + result
+        text = (
+            "ENTRY %main (p0: bf16[32]) -> bf16[32] {\n"
+            "  %p0 = bf16[32]{0} parameter(0)\n"
+            f"  %cps = ({operand}, {operand}, u32[]{{:S(2)}}, "
+            "u32[]{:S(2)}) collective-permute-start(%p0), "
+            "source_target_pairs={{0,2},{2,0}}\n"
+            f"  ROOT %cpd = {operand} collective-permute-done(%cps)\n"
+            "}\n")
+        rep = hlo.pod_exchange_report(text, 2)
+        assert rep.permute_cross_bytes == want
+        assert rep.pod_axis_only and rep.unparsed == 0
+        cost = hlo.analyze(text)
+        assert cost.collective_bytes["collective-permute"] == want
+
 
 class TestLaunchShim:
     def test_reexports(self):
