@@ -19,8 +19,7 @@ import time
 
 from benchmarks import (fig1_accuracy, fig2_flickr, fig4_bn_divergence,
                         fig5_groupnorm, fig6_skew_degree, fig8_skewscout,
-                        fig_topology, kernels_bench, roofline,
-                        tab678_hparams)
+                        fig_topology, kernels_bench, tab678_hparams)
 
 BENCHES = {  # priority order: cheap + headline results first
     "kernels": (kernels_bench, "pallas kernels vs oracles"),
@@ -32,7 +31,6 @@ BENCHES = {  # priority order: cheap + headline results first
     "fig2": (fig2_flickr, "geo-skew (Flickr-Mammal analogue)"),
     "fig_topology": (fig_topology, "D-PSGD topology x skew sweep"),
     "tab678": (tab678_hparams, "theta sensitivity"),
-    "roofline": (roofline, "dry-run roofline table"),
 }
 
 
@@ -52,13 +50,6 @@ def _headline(name, rows):
         return ";".join(
             f"skew{r['skew']}:ss={r['skewscout_savings']:.1f}x,"
             f"oracle={r['oracle_savings']:.1f}x" for r in rows)
-    if name == "roofline":
-        ok = [r for r in rows if r.get("ok")]
-        fail = len(rows) - len(ok)
-        from collections import Counter
-        c = Counter(r["bottleneck"] for r in ok)
-        return f"ok={len(ok)};fail={fail};" + \
-            ";".join(f"{k}={v}" for k, v in sorted(c.items()))
     if "val_acc" in rows[0]:
         worst = min(rows, key=lambda r: r["val_acc"])
         keys = [k for k in ("model", "algo", "skew", "setting", "theta")
@@ -89,7 +80,7 @@ def main(argv=None) -> int:
     for name in names:
         mod, _desc = BENCHES[name]
         t0 = time.perf_counter()
-        if args.use_cache and name not in ("kernels", "roofline"):
+        if args.use_cache and name != "kernels":
             from benchmarks.common import load_rows
             rows = load_rows(name)
             if rows is None:

@@ -130,21 +130,26 @@ class ADPSGD(DPSGD):
     def _step_stale(self, state, batch, lr, step_idx,
                     nbr_idx, nbr_w, self_w, stale) -> Tuple[Dict, Dict]:
         self.trace_count += 1          # Python side effect: trace-time only
-        losses, new_ms, vel, params = self._local_update(state, batch, lr)
-        flat, treedef, leaves = self._flatten(params)
-        # push this round's post-gradient stack into slot 0; slot s now
-        # holds the stack from s rounds ago (pre-mix, like slot 0)
-        snaps = jnp.concatenate([flat[None], state["snaps"][:-1]], axis=0)
-        src = snaps.reshape(-1, flat.shape[1])     # ((S+1)*K, N)
-        gidx = stale * self.K + nbr_idx            # slot-offset gather
-        if self.use_kernel:
-            mixed = ops.neighbor_mix(flat, gidx, nbr_w, self_w, src=src)
-        else:
-            # dense oracle: scatter the runtime weights into (K, (S+1)K)
-            W = jnp.zeros((self.K, src.shape[0]), jnp.float32).at[
-                jnp.arange(self.K)[:, None], gidx].add(nbr_w)
-            mixed = jnp.matmul(W, src) + self_w[:, None] * flat
-        params = self._unflatten(mixed, treedef, leaves)
+        with jax.named_scope("local_step"):
+            losses, new_ms, vel, params = self._local_update(state, batch,
+                                                             lr)
+        with jax.named_scope("exchange"):
+            flat, treedef, leaves = self._flatten(params)
+            # push this round's post-gradient stack into slot 0; slot s
+            # now holds the stack from s rounds ago (pre-mix, like slot 0)
+            snaps = jnp.concatenate([flat[None], state["snaps"][:-1]],
+                                    axis=0)
+            src = snaps.reshape(-1, flat.shape[1])     # ((S+1)*K, N)
+            gidx = stale * self.K + nbr_idx            # slot-offset gather
+            if self.use_kernel:
+                mixed = ops.neighbor_mix(flat, gidx, nbr_w, self_w, src=src)
+            else:
+                # dense oracle: scatter the runtime weights into
+                # (K, (S+1)K)
+                W = jnp.zeros((self.K, src.shape[0]), jnp.float32).at[
+                    jnp.arange(self.K)[:, None], gidx].add(nbr_w)
+                mixed = jnp.matmul(W, src) + self_w[:, None] * flat
+            params = self._unflatten(mixed, treedef, leaves)
 
         metrics = self._gossip_metrics(losses, params, nbr_w)
         nbr_mask = (nbr_w > 0).astype(jnp.float32)

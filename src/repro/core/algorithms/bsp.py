@@ -34,16 +34,19 @@ class BSP:
 
     @partial(jax.jit, static_argnums=0)
     def step(self, state, batch, lr, step_idx) -> Tuple[Dict, Dict]:
-        losses, grads, new_ms = pernode_grads(
-            self.fns, state["params"], state["mstate"], batch,
-            params_stacked=False)
-        g = tree_mean0(grads)
+        with jax.named_scope("local_step"):
+            losses, grads, new_ms = pernode_grads(
+                self.fns, state["params"], state["mstate"], batch,
+                params_stacked=False)
+        with jax.named_scope("exchange"):
+            g = tree_mean0(grads)
 
         def upd(w, gl, u):
             gl = gl + self.wd * w
             return self.m * u - lr * gl
-        vel = tmap(upd, state["params"], g, state["vel"])
-        params = tmap(lambda w, u: w + u, state["params"], vel)
+        with jax.named_scope("local_step"):
+            vel = tmap(upd, state["params"], g, state["vel"])
+            params = tmap(lambda w, u: w + u, state["params"], vel)
         new_state = {"params": params, "mstate": new_ms, "vel": vel}
         metrics = {"loss": jnp.mean(losses),
                    "comm_floats": jnp.asarray(

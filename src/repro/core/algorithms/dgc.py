@@ -68,64 +68,66 @@ class DGC:
     def step(self, state, batch, lr, step_idx, sparsity=None
              ) -> Tuple[Dict, Dict]:
         s = self.sparsity if sparsity is None else sparsity
-        losses, grads, new_ms = pernode_grads(
-            self.fns, state["params"], state["mstate"], batch,
-            params_stacked=False)
+        with jax.named_scope("local_step"):
+            losses, grads, new_ms = pernode_grads(
+                self.fns, state["params"], state["mstate"], batch,
+                params_stacked=False)
 
-        # g = -eta * grad, with per-node gradient clipping
-        def clip_node(g):
-            n = global_norm(g)
-            scale = jnp.minimum(1.0, self.clip / jnp.maximum(n, 1e-12))
-            return tmap(lambda l: l * scale, g)
-        grads = jax.vmap(clip_node)(grads)
-        g = tmap(lambda gl, w: -lr * (gl + self.wd * w[None]),
-                 grads, state["params"])
+            # g = -eta * grad, with per-node gradient clipping
+            def clip_node(g):
+                n = global_norm(g)
+                scale = jnp.minimum(1.0, self.clip / jnp.maximum(n, 1e-12))
+                return tmap(lambda l: l * scale, g)
+            grads = jax.vmap(clip_node)(grads)
+            g = tmap(lambda gl, w: -lr * (gl + self.wd * w[None]),
+                     grads, state["params"])
 
-        vel = tmap(lambda u, gl: self.m * u + gl, state["vel"], g)
-        acc = tmap(lambda v, u: v + u, state["acc"], vel)
+            vel = tmap(lambda u, gl: self.m * u + gl, state["vel"], g)
+            acc = tmap(lambda v, u: v + u, state["acc"], vel)
 
-        if self.compressor == "randk":
-            # seeded rand-k: each (step, leaf) gets its own counter
-            # stream, and replaying the stream on ``vel`` clears exactly
-            # the exchanged coordinates (momentum factor masking without
-            # a materialized mask).
-            keep = 1.0 - s
-            leaves_v, treedef = jax.tree_util.tree_flatten(acc)
-            leaves_u = treedef.flatten_up_to(vel)
-            sh, cl, counts = [], [], []
-            for li, (v, u) in enumerate(zip(leaves_v, leaves_u)):
-                leaf_seed = (jnp.asarray(step_idx, jnp.int32) * 1009
-                             + self.seed * 131 + li)
-                sv, cnt = ops.rand_k_sparsify(v, keep, leaf_seed)
-                su, _ = ops.rand_k_sparsify(u, keep, leaf_seed)
-                sh.append(sv)
-                cl.append(su)
-                counts.append(cnt)
-            shared = jax.tree_util.tree_unflatten(treedef, sh)
-            total = tree_sum0(shared)                    # sum over nodes
-            params = tmap(lambda w, t: w + t, state["params"], total)
-            acc = tmap(lambda v, sv: v - sv, acc, shared)
-            vel = jax.tree_util.tree_unflatten(
-                treedef, [u - su for u, su in zip(leaves_u, cl)])
-            comm = sum(c.astype(jnp.float32) for c in counts) / self.K
-        else:
-            # per-tensor, per-node top-(1-s) magnitude threshold
-            def threshold(v):
-                flat = jnp.abs(v.reshape(v.shape[0], -1))
-                return jnp.quantile(flat, s, axis=1)     # (K,)
-            def select(v):
-                t = threshold(v)
-                return (jnp.abs(v) > t.reshape((-1,) + (1,) * (v.ndim - 1))
-                        ).astype(v.dtype)
-            mask = tmap(select, acc)
-            shared = tmap(lambda v, m_: v * m_, acc, mask)
-            total = tree_sum0(shared)                    # sum over nodes
-            params = tmap(lambda w, t: w + t, state["params"], total)
-            # momentum factor masking: clear exchanged entries from v AND u
-            acc = tmap(lambda v, m_: v * (1 - m_), acc, mask)
-            vel = tmap(lambda u, m_: u * (1 - m_), vel, mask)
-            comm = sum(jnp.sum(m_)
-                       for m_ in jax.tree_util.tree_leaves(mask)) / self.K
+        with jax.named_scope("exchange"):
+            if self.compressor == "randk":
+                # seeded rand-k: each (step, leaf) gets its own counter
+                # stream, and replaying the stream on ``vel`` clears exactly
+                # the exchanged coordinates (momentum factor masking without
+                # a materialized mask).
+                keep = 1.0 - s
+                leaves_v, treedef = jax.tree_util.tree_flatten(acc)
+                leaves_u = treedef.flatten_up_to(vel)
+                sh, cl, counts = [], [], []
+                for li, (v, u) in enumerate(zip(leaves_v, leaves_u)):
+                    leaf_seed = (jnp.asarray(step_idx, jnp.int32) * 1009
+                                 + self.seed * 131 + li)
+                    sv, cnt = ops.rand_k_sparsify(v, keep, leaf_seed)
+                    su, _ = ops.rand_k_sparsify(u, keep, leaf_seed)
+                    sh.append(sv)
+                    cl.append(su)
+                    counts.append(cnt)
+                shared = jax.tree_util.tree_unflatten(treedef, sh)
+                total = tree_sum0(shared)                    # sum over nodes
+                params = tmap(lambda w, t: w + t, state["params"], total)
+                acc = tmap(lambda v, sv: v - sv, acc, shared)
+                vel = jax.tree_util.tree_unflatten(
+                    treedef, [u - su for u, su in zip(leaves_u, cl)])
+                comm = sum(c.astype(jnp.float32) for c in counts) / self.K
+            else:
+                # per-tensor, per-node top-(1-s) magnitude threshold
+                def threshold(v):
+                    flat = jnp.abs(v.reshape(v.shape[0], -1))
+                    return jnp.quantile(flat, s, axis=1)     # (K,)
+                def select(v):
+                    t = threshold(v)
+                    return (jnp.abs(v) > t.reshape((-1,) + (1,) * (v.ndim - 1))
+                            ).astype(v.dtype)
+                mask = tmap(select, acc)
+                shared = tmap(lambda v, m_: v * m_, acc, mask)
+                total = tree_sum0(shared)                    # sum over nodes
+                params = tmap(lambda w, t: w + t, state["params"], total)
+                # momentum factor masking: clear exchanged entries from v AND u
+                acc = tmap(lambda v, m_: v * (1 - m_), acc, mask)
+                vel = tmap(lambda u, m_: u * (1 - m_), vel, mask)
+                comm = sum(jnp.sum(m_)
+                           for m_ in jax.tree_util.tree_leaves(mask)) / self.K
         metrics = {"loss": jnp.mean(losses), "comm_floats": comm,
                    "resid_delta": _mean_rel(acc, params)}
         return ({"params": params, "mstate": new_ms, "vel": vel, "acc": acc},

@@ -212,8 +212,11 @@ class DPSGD:
     def _step(self, state, batch, lr, step_idx, nbr_idx, nbr_w, self_w
               ) -> Tuple[Dict, Dict]:
         self.trace_count += 1          # Python side effect: trace-time only
-        losses, new_ms, vel, params = self._local_update(state, batch, lr)
-        params = self._mix(params, nbr_idx, nbr_w, self_w)
+        with jax.named_scope("local_step"):
+            losses, new_ms, vel, params = self._local_update(state, batch,
+                                                             lr)
+        with jax.named_scope("exchange"):
+            params = self._mix(params, nbr_idx, nbr_w, self_w)
         metrics = self._gossip_metrics(losses, params, nbr_w)
         return ({"params": params, "mstate": new_ms, "vel": vel}, metrics)
 

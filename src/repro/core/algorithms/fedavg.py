@@ -40,12 +40,13 @@ class FedAvg:
              ) -> Tuple[Dict, Dict]:
         il = jnp.asarray(self.iter_local if iter_local is None else iter_local,
                          jnp.int32)
-        losses, grads, new_ms = pernode_grads(
-            self.fns, state["params"], state["mstate"], batch,
-            params_stacked=True)
-        vel = tmap(lambda w, g, u: self.m * u - lr * (g + self.wd * w),
-                   state["params"], grads, state["vel"])
-        params = tmap(lambda w, u: w + u, state["params"], vel)
+        with jax.named_scope("local_step"):
+            losses, grads, new_ms = pernode_grads(
+                self.fns, state["params"], state["mstate"], batch,
+                params_stacked=True)
+            vel = tmap(lambda w, g, u: self.m * u - lr * (g + self.wd * w),
+                       state["params"], grads, state["vel"])
+            params = tmap(lambda w, u: w + u, state["params"], vel)
 
         do_sync = (step_idx % il) == (il - 1)
 
@@ -57,8 +58,9 @@ class FedAvg:
             a = tree_mean0(p)
             return tmap(lambda l, m_: jnp.broadcast_to(m_, l.shape), p, a)
 
-        params = jax.lax.cond(do_sync, sync, lambda p: p, params)
-        new_ms = jax.lax.cond(do_sync, sync, lambda s: s, new_ms)
+        with jax.named_scope("exchange"):
+            params = jax.lax.cond(do_sync, sync, lambda p: p, params)
+            new_ms = jax.lax.cond(do_sync, sync, lambda s: s, new_ms)
         comm = jnp.where(do_sync,
                          float(tree_size(avg)), 0.0).astype(jnp.float32)
         metrics = {"loss": jnp.mean(losses), "comm_floats": comm,

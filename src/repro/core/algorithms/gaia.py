@@ -51,29 +51,32 @@ class Gaia:
         # threshold decays with the learning rate (Algorithm 1, line 16)
         thresh = t0 * (lr / self.lr0) if self.lr0 is not None else t0
 
-        losses, grads, new_ms = pernode_grads(
-            self.fns, state["params"], state["mstate"], batch,
-            params_stacked=True)
+        with jax.named_scope("local_step"):
+            losses, grads, new_ms = pernode_grads(
+                self.fns, state["params"], state["mstate"], batch,
+                params_stacked=True)
+            vel = tmap(lambda w, g, u: self.m * u - lr * (g + self.wd * w),
+                       state["params"], grads, state["vel"])
+            params = tmap(lambda w, u: w + u, state["params"], vel)
+            acc = tmap(lambda v, u: v + u, state["acc"], vel)
 
-        vel = tmap(lambda w, g, u: self.m * u - lr * (g + self.wd * w),
-                   state["params"], grads, state["vel"])
-        params = tmap(lambda w, u: w + u, state["params"], vel)
-        acc = tmap(lambda v, u: v + u, state["acc"], vel)
-
-        # significance filter: |v / w| > thresh — the fused select kernel
-        # (or its dispatched jnp twin) returns (v * mask, count) per leaf,
-        # so the mask itself never materializes: the shared part is
-        # cleared exactly via acc - shared (shared = acc * mask).
-        leaves_v, treedef = jax.tree_util.tree_flatten(acc)
-        leaves_w = treedef.flatten_up_to(params)
-        picked = [ops.gaia_select(v, w, thresh)
-                  for v, w in zip(leaves_v, leaves_w)]
-        shared = jax.tree_util.tree_unflatten(treedef,
-                                              [p[0] for p in picked])
-        total = tmap(lambda s: jnp.sum(s, axis=0, keepdims=True), shared)
-        # apply everyone else's significant updates; clear own shared part
-        params = tmap(lambda w, t, s: w + (t - s), params, total, shared)
-        acc = tmap(lambda v, s: v - s, acc, shared)
+        with jax.named_scope("exchange"):
+            # significance filter: |v / w| > thresh — the fused select
+            # kernel (or its dispatched jnp twin) returns (v * mask,
+            # count) per leaf, so the mask itself never materializes: the
+            # shared part is cleared exactly via acc - shared (shared =
+            # acc * mask).
+            leaves_v, treedef = jax.tree_util.tree_flatten(acc)
+            leaves_w = treedef.flatten_up_to(params)
+            picked = [ops.gaia_select(v, w, thresh)
+                      for v, w in zip(leaves_v, leaves_w)]
+            shared = jax.tree_util.tree_unflatten(treedef,
+                                                  [p[0] for p in picked])
+            total = tmap(lambda s: jnp.sum(s, axis=0, keepdims=True), shared)
+            # apply everyone else's significant updates; clear own shared
+            # part
+            params = tmap(lambda w, t, s: w + (t - s), params, total, shared)
+            acc = tmap(lambda v, s: v - s, acc, shared)
 
         comm = sum(p[1].astype(jnp.float32) for p in picked) / self.K
         metrics = {"loss": jnp.mean(losses), "comm_floats": comm,

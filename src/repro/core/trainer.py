@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import CommConfig
 from repro.configs.cnn_zoo import CNNConfig
 from repro.core.algorithms.adpsgd import ADPSGD
@@ -289,67 +290,93 @@ def train_decentralized(cnn_cfg: CNNConfig, algo_name: str,
     steps_per_epoch = loader.steps_per_epoch
 
     for t in range(steps):
-        xs, ys = loader.next_stacked()
-        sbatch = {"x": jnp.asarray(xs), "y": jnp.asarray(ys)}
-        lr_t = jnp.asarray(lr_fn(t), jnp.float32)
-        kw: Dict[str, Any] = {}
-        if algo_name == "gaia":
-            kw["t0"] = jnp.asarray(scout.theta if scout else comm.gaia_t0,
-                                   jnp.float32)
-        elif algo_name == "fedavg":
-            kw["iter_local"] = jnp.asarray(
-                scout.theta if scout else comm.iter_local, jnp.int32)
-        elif algo_name == "dgc":
-            epoch = t // steps_per_epoch
-            s = (scout.theta if scout
-                 else warmup_sparsity(epoch, comm.dgc_warmup_epochs))
-            kw["sparsity"] = jnp.asarray(s, jnp.float32)
-        t_step = time.perf_counter()
-        state, metrics = algo.step(state, sbatch, lr_t,
-                                   jnp.asarray(t, jnp.int32), **kw)
-        jax.block_until_ready(state)
-        step_s.append(time.perf_counter() - t_step)
-        cf = float(metrics["comm_floats"])
-        comm_total += cf
-        if algo_name in GOSSIP_ALGOS:
-            # round t's active edge set prices this gossip exchange; an
-            # async algorithm also reports its per-edge staleness bound
-            # so the ledger can amortize link latency accordingly
-            stale = algo.edge_staleness(t) \
-                if algo_name == "adpsgd" else None
-            ledger.record_gossip(float(tree_size(params)), t=t,
-                                 staleness=stale)
-            gap_curve.append(
-                (t, float(algo.schedule.round_spectral_gap(t))))
-            if algo_name == "adpsgd":
-                stale_curve.append((t, float(metrics["mean_staleness"])))
-        elif cf > 0:
-            ledger.record_exchange(cf)
-        if scout:
-            scout.record_step(cf)
-            rep = scout.maybe_travel(
-                t, algo, state,
-                lambda node, _t=t: loader.sample_train_subset(
-                    node, 256, seed=_t))
-            if rep is not None:
-                # model traveling overhead: the scout booked each
-                # probe's shipment on the edge it crossed
-                comm_total += rep.probe_floats
-                if algo_name == "dpsgd" and rep.new_theta is not rep.theta:
-                    # topology rung switch: re-wiring is charged by the
-                    # ledger on the next gossip round, inside the new
-                    # rung's C(θ) window
-                    algo.set_schedule(rep.new_theta)
-                    ledger.switch_schedule(rep.new_theta)
-                elif algo_name == "adpsgd" and rep.new_theta != rep.theta:
-                    # staleness rung switch: same fabric, new bound —
-                    # runtime operand values only, no re-wiring
-                    algo.set_staleness(rep.new_theta)
-        if (t + 1) % eval_every == 0 or t == steps - 1:
-            p, s = algo.eval_params(state)
-            acc = eval_acc(p, s, val[0], val[1])
-            acc_curve.append((t + 1, acc))
-        loss_curve.append((t, float(metrics["loss"])))
+        obs.set_round(t)
+        with obs.span("trainer.round"):
+            with obs.span("trainer.load"):
+                xs, ys = loader.next_stacked()
+            with obs.span("trainer.put"):
+                sbatch = {"x": jnp.asarray(xs), "y": jnp.asarray(ys)}
+            lr = lr_fn(t)
+            with obs.span("trainer.put"):
+                lr_t = jnp.asarray(lr, jnp.float32)
+                kw: Dict[str, Any] = {}
+                if algo_name == "gaia":
+                    kw["t0"] = jnp.asarray(
+                        scout.theta if scout else comm.gaia_t0, jnp.float32)
+                elif algo_name == "fedavg":
+                    kw["iter_local"] = jnp.asarray(
+                        scout.theta if scout else comm.iter_local,
+                        jnp.int32)
+                elif algo_name == "dgc":
+                    epoch = t // steps_per_epoch
+                    s = (scout.theta if scout
+                         else warmup_sparsity(epoch, comm.dgc_warmup_epochs))
+                    kw["sparsity"] = jnp.asarray(s, jnp.float32)
+            if obs.active():
+                # this round's puts: x, y, lr_t, the strategy's scalar
+                # and, in the step call, the round index (4 bytes each)
+                obs.count("h2d_puts", 4 + len(kw))
+                obs.count("h2d_bytes", sbatch["x"].nbytes +
+                          sbatch["y"].nbytes + 4 * (2 + len(kw)))
+            t_step = time.perf_counter()
+            with obs.span("trainer.dispatch"):
+                state, metrics = algo.step(state, sbatch, lr_t,
+                                           jnp.asarray(t, jnp.int32), **kw)
+            with obs.span("trainer.wait"):
+                jax.block_until_ready(state)
+            step_s.append(time.perf_counter() - t_step)
+            with obs.span("trainer.sync"):
+                cf = float(metrics["comm_floats"])
+                loss = float(metrics["loss"])
+                if algo_name == "adpsgd":
+                    stale_curve.append(
+                        (t, float(metrics["mean_staleness"])))
+            if obs.active():
+                obs.count("d2h_syncs", 3 if algo_name == "adpsgd" else 2)
+            comm_total += cf
+            with obs.span("trainer.ledger"):
+                if algo_name in GOSSIP_ALGOS:
+                    # round t's active edge set prices this gossip
+                    # exchange; an async algorithm also reports its
+                    # per-edge staleness bound so the ledger can amortize
+                    # link latency accordingly
+                    stale = algo.edge_staleness(t) \
+                        if algo_name == "adpsgd" else None
+                    ledger.record_gossip(float(tree_size(params)), t=t,
+                                         staleness=stale)
+                    gap_curve.append(
+                        (t, float(algo.schedule.round_spectral_gap(t))))
+                elif cf > 0:
+                    ledger.record_exchange(cf)
+            if scout:
+                with obs.span("trainer.scout"):
+                    scout.record_step(cf)
+                    rep = scout.maybe_travel(
+                        t, algo, state,
+                        lambda node, _t=t: loader.sample_train_subset(
+                            node, 256, seed=_t))
+                if rep is not None:
+                    # model traveling overhead: the scout booked each
+                    # probe's shipment on the edge it crossed
+                    comm_total += rep.probe_floats
+                    if algo_name == "dpsgd" and \
+                            rep.new_theta is not rep.theta:
+                        # topology rung switch: re-wiring is charged by
+                        # the ledger on the next gossip round, inside the
+                        # new rung's C(θ) window
+                        algo.set_schedule(rep.new_theta)
+                        ledger.switch_schedule(rep.new_theta)
+                    elif algo_name == "adpsgd" and \
+                            rep.new_theta != rep.theta:
+                        # staleness rung switch: same fabric, new bound —
+                        # runtime operand values only, no re-wiring
+                        algo.set_staleness(rep.new_theta)
+            if (t + 1) % eval_every == 0 or t == steps - 1:
+                with obs.span("trainer.eval"):
+                    p, s = algo.eval_params(state)
+                    acc = eval_acc(p, s, val[0], val[1])
+                acc_curve.append((t + 1, acc))
+            loss_curve.append((t, loss))
 
     if not acc_curve:
         raise RuntimeError(

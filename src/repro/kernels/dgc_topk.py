@@ -86,6 +86,7 @@ def abs_histogram(v: jnp.ndarray, v_max: jnp.ndarray, *,
         out_specs=pl.BlockSpec((1, n_bins), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_blocks, n_bins), jnp.int32),
         interpret=interpret,
+        name="abs_histogram",
     )(v2, vmax_arr)
     total = jnp.sum(hist, axis=0)
     pad_count = rows_pad * LANES - n
@@ -144,6 +145,7 @@ def abs_histogram_fused(v: jnp.ndarray, *, n_bins: int = N_BINS,
         ],
         scratch_shapes=[pltpu.SMEM((1,), jnp.float32)],
         interpret=interpret,
+        name="abs_histogram_fused",
     )(v2)
     total = jnp.sum(hist, axis=0)
     pad_count = v2.size - n
@@ -192,6 +194,7 @@ def rand_k_select(v: jnp.ndarray, keep_prob: jnp.ndarray,
         ],
         out_shape=[jax.ShapeDtypeStruct(v2.shape, v.dtype), cnt_shape],
         interpret=interpret,
+        name="rand_k_select",
     )(v2, seed_arr, p_arr)
     return out.reshape(-1)[:n].reshape(orig_shape), jnp.sum(cnt)
 
@@ -225,6 +228,7 @@ def dgc_select(v: jnp.ndarray, threshold: jnp.ndarray, *,
         ],
         out_shape=[jax.ShapeDtypeStruct(v2.shape, v.dtype), cnt_shape],
         interpret=interpret,
+        name="dgc_select",
     )(v2, t_arr)
     return out.reshape(-1)[:n].reshape(orig_shape), jnp.sum(cnt)
 

@@ -123,5 +123,6 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qp, kp, vp)
     return out.reshape(B, H, -1, D)[:, :, :Tq]

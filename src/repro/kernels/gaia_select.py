@@ -82,6 +82,7 @@ def gaia_select(v: jnp.ndarray, w: jnp.ndarray, threshold: jnp.ndarray, *,
         ],
         out_shape=[jax.ShapeDtypeStruct(v2.shape, v.dtype), cnt_shape],
         interpret=interpret,
+        name="gaia_select",
     )(v2, w2, t_arr)
     selected = out.reshape(-1)[:n].reshape(orig_shape)
     return selected, jnp.sum(cnt)

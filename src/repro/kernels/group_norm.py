@@ -44,5 +44,6 @@ def group_norm(x: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray, *,
         out_specs=pl.BlockSpec((1, H * W, C), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
         interpret=interpret,
+        name="group_norm",
     )(x2, scale, bias)
     return out.reshape(B, H, W, C)

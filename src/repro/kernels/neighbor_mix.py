@@ -105,6 +105,7 @@ def neighbor_mix(x: jnp.ndarray, nbr_idx: jnp.ndarray, nbr_w: jnp.ndarray,
             out_specs=block3(K),
             out_shape=jax.ShapeDtypeStruct(x3.shape, x.dtype),
             interpret=interpret,
+            name="neighbor_mix",
         )(*operands, x3)
     else:
         M = src.shape[0]
@@ -118,5 +119,6 @@ def neighbor_mix(x: jnp.ndarray, nbr_idx: jnp.ndarray, nbr_w: jnp.ndarray,
             out_specs=block3(K),
             out_shape=jax.ShapeDtypeStruct(x3.shape, x.dtype),
             interpret=interpret,
+            name="neighbor_mix",
         )(*operands, x3, src3)
     return out.reshape(K, rows_pad * LANES)[:, :N]
