@@ -283,11 +283,84 @@ def train_decentralized(cnn_cfg: CNNConfig, algo_name: str,
                           ledger=ledger, participation=part)
 
     loss_curve, acc_curve, gap_curve, stale_curve = [], [], [], []
-    # host wall time of each step, ended by block_until_ready; step 0
+    # host time of each round's ``trainer.dispatch`` plus its
+    # ``trainer.wait``, not one wall interval: a pipelined round's
+    # interval also holds the next round's host work.  The wait in round
+    # t is for the state of the round it finishes (t - 1 when pipelined;
+    # the last round also waits for its own).  Round 0's dispatch
     # includes tracing and compiling the step
     step_s: List[float] = []
     comm_total = 0.0
     steps_per_epoch = loader.steps_per_epoch
+    # One round of software pipelining: round t is dispatched before
+    # round t - 1's scalars are read, so the host's loading, puts and
+    # dispatch overlap the chip's previous step.  Only a SkewScout
+    # controller feeds a round's results into the next round's inputs
+    # (theta, the topology or staleness rung), so a run with one keeps
+    # lock-step
+    lag = 1 if scout is None else 0
+    scalars = ("comm_floats", "loss") + \
+        (("mean_staleness",) if algo_name == "adpsgd" else ())
+    pending: List[Tuple[int, Any, Dict]] = []
+    overlapped = 0
+
+    def finish(u: int, st, metrics) -> None:
+        """Round ``u``'s host side once its step is dispatched: wait for
+        its state, read its scalars, price it, steer and evaluate."""
+        nonlocal comm_total
+        t_wait = time.perf_counter()
+        with obs.span("trainer.wait"):
+            jax.block_until_ready(st)
+        step_s[-1] += time.perf_counter() - t_wait
+        with obs.span("trainer.sync"):
+            cf = float(metrics["comm_floats"])
+            loss = float(metrics["loss"])
+            if algo_name == "adpsgd":
+                stale_curve.append((u, float(metrics["mean_staleness"])))
+        if obs.active():
+            obs.count("d2h_syncs", len(scalars))
+        comm_total += cf
+        with obs.span("trainer.ledger"):
+            if algo_name in GOSSIP_ALGOS:
+                # round u's active edge set prices this gossip exchange;
+                # an async algorithm also reports its per-edge staleness
+                # bound so the ledger can amortize link latency
+                # accordingly
+                stale = algo.edge_staleness(u) \
+                    if algo_name == "adpsgd" else None
+                ledger.record_gossip(float(tree_size(params)), t=u,
+                                     staleness=stale)
+                gap_curve.append(
+                    (u, float(algo.schedule.round_spectral_gap(u))))
+            elif cf > 0:
+                ledger.record_exchange(cf)
+        if scout:
+            with obs.span("trainer.scout"):
+                scout.record_step(cf)
+                rep = scout.maybe_travel(
+                    u, algo, st,
+                    lambda node, _t=u: loader.sample_train_subset(
+                        node, 256, seed=_t))
+            if rep is not None:
+                # model traveling overhead: the scout booked each
+                # probe's shipment on the edge it crossed
+                comm_total += rep.probe_floats
+                if algo_name == "dpsgd" and rep.new_theta is not rep.theta:
+                    # topology rung switch: re-wiring is charged by the
+                    # ledger on the next gossip round, inside the new
+                    # rung's C(θ) window
+                    algo.set_schedule(rep.new_theta)
+                    ledger.switch_schedule(rep.new_theta)
+                elif algo_name == "adpsgd" and rep.new_theta != rep.theta:
+                    # staleness rung switch: same fabric, new bound —
+                    # runtime operand values only, no re-wiring
+                    algo.set_staleness(rep.new_theta)
+        if (u + 1) % eval_every == 0 or u == steps - 1:
+            with obs.span("trainer.eval"):
+                p, s = algo.eval_params(st)
+                acc = eval_acc(p, s, val[0], val[1])
+            acc_curve.append((u + 1, acc))
+        loss_curve.append((u, loss))
 
     for t in range(steps):
         obs.set_round(t)
@@ -318,65 +391,24 @@ def train_decentralized(cnn_cfg: CNNConfig, algo_name: str,
                 obs.count("h2d_puts", 4 + len(kw))
                 obs.count("h2d_bytes", sbatch["x"].nbytes +
                           sbatch["y"].nbytes + 4 * (2 + len(kw)))
+            if pending:
+                # dispatched while the previous round's scalars are unread
+                overlapped += 1
+                if obs.active():
+                    obs.count("rounds_ahead")
             t_step = time.perf_counter()
             with obs.span("trainer.dispatch"):
                 state, metrics = algo.step(state, sbatch, lr_t,
                                            jnp.asarray(t, jnp.int32), **kw)
-            with obs.span("trainer.wait"):
-                jax.block_until_ready(state)
             step_s.append(time.perf_counter() - t_step)
-            with obs.span("trainer.sync"):
-                cf = float(metrics["comm_floats"])
-                loss = float(metrics["loss"])
-                if algo_name == "adpsgd":
-                    stale_curve.append(
-                        (t, float(metrics["mean_staleness"])))
-            if obs.active():
-                obs.count("d2h_syncs", 3 if algo_name == "adpsgd" else 2)
-            comm_total += cf
-            with obs.span("trainer.ledger"):
-                if algo_name in GOSSIP_ALGOS:
-                    # round t's active edge set prices this gossip
-                    # exchange; an async algorithm also reports its
-                    # per-edge staleness bound so the ledger can amortize
-                    # link latency accordingly
-                    stale = algo.edge_staleness(t) \
-                        if algo_name == "adpsgd" else None
-                    ledger.record_gossip(float(tree_size(params)), t=t,
-                                         staleness=stale)
-                    gap_curve.append(
-                        (t, float(algo.schedule.round_spectral_gap(t))))
-                elif cf > 0:
-                    ledger.record_exchange(cf)
-            if scout:
-                with obs.span("trainer.scout"):
-                    scout.record_step(cf)
-                    rep = scout.maybe_travel(
-                        t, algo, state,
-                        lambda node, _t=t: loader.sample_train_subset(
-                            node, 256, seed=_t))
-                if rep is not None:
-                    # model traveling overhead: the scout booked each
-                    # probe's shipment on the edge it crossed
-                    comm_total += rep.probe_floats
-                    if algo_name == "dpsgd" and \
-                            rep.new_theta is not rep.theta:
-                        # topology rung switch: re-wiring is charged by
-                        # the ledger on the next gossip round, inside the
-                        # new rung's C(θ) window
-                        algo.set_schedule(rep.new_theta)
-                        ledger.switch_schedule(rep.new_theta)
-                    elif algo_name == "adpsgd" and \
-                            rep.new_theta != rep.theta:
-                        # staleness rung switch: same fabric, new bound —
-                        # runtime operand values only, no re-wiring
-                        algo.set_staleness(rep.new_theta)
-            if (t + 1) % eval_every == 0 or t == steps - 1:
-                with obs.span("trainer.eval"):
-                    p, s = algo.eval_params(state)
-                    acc = eval_acc(p, s, val[0], val[1])
-                acc_curve.append((t + 1, acc))
-            loss_curve.append((t, loss))
+            # the scalars' copies queue behind this step, ahead of the
+            # next, and have landed by the time they are read
+            for k in scalars:
+                metrics[k].copy_to_host_async()
+            pending.append((t, state, metrics))
+            # the last round drains what is still in flight
+            while len(pending) > (lag if t < steps - 1 else 0):
+                finish(*pending.pop(0))
 
     if not acc_curve:
         raise RuntimeError(
@@ -403,6 +435,7 @@ def train_decentralized(cnn_cfg: CNNConfig, algo_name: str,
         skewscout_history=list(scout.history) if scout else [],
         extras={"ledger": ledger.summary(),
                 "step_s": step_s,
+                "rounds_overlapped": overlapped,
                 "mosaic_calls": mosaic_calls,
                 "spectral_gap": final_sched.spectral_gap(),
                 "spectral_gap_curve": gap_curve,

@@ -196,6 +196,8 @@ ROUNDS = 6
 PER_ROUND = {"trainer.round": 1, "trainer.load": 1, "trainer.put": 2,
              "trainer.dispatch": 1, "trainer.wait": 1, "trainer.sync": 1,
              "trainer.ledger": 1}
+#: the spans of the round a round finishes (the one dispatched before it)
+FINISHING = ("trainer.wait", "trainer.sync", "trainer.ledger")
 
 
 @pytest.fixture(scope="module")
@@ -226,13 +228,19 @@ def test_trainer_rounds_under_a_recorder(task, algo):
     assert rec.whole_rounds() == list(range(ROUNDS))
     for t in range(ROUNDS):
         names = [n for n, rt, _, _, _ in rec.spans if rt == t]
-        want = dict(PER_ROUND, **({"trainer.eval": 1}
-                                  if t == ROUNDS - 1 else {}))
+        # each round finishes the one before it: round 0 has none to
+        # finish, and the last round finishes itself too
+        finishing = 0 if t == 0 else 2 if t == ROUNDS - 1 else 1
+        want = dict(PER_ROUND, **{k: finishing for k in FINISHING},
+                    **({"trainer.eval": 1} if t == ROUNDS - 1 else {}))
+        want = {k: n for k, n in want.items() if n}
         assert {n: names.count(n) for n in set(names)} == want, t
     s = rec.summary()
     puts = 4 + (algo == "gaia")        # x, y, lr, the round, Gaia's t0
     assert s["counts"]["h2d_puts"] == [puts] * ROUNDS
-    assert s["counts"]["d2h_syncs"] == [2] * ROUNDS
+    assert s["counts"]["d2h_syncs"] == [0] + [2] * (ROUNDS - 2) + [4]
+    assert s["counts"]["rounds_ahead"] == [0] + [1] * (ROUNDS - 1)
+    assert r.extras["rounds_overlapped"] == ROUNDS - 1
     x = task[0][0][0]
     assert s["counts"]["h2d_bytes"] == [5 * 4 * x[0].nbytes + 5 * 4 * 4
                                         + 4 * (puts - 2)] * ROUNDS
