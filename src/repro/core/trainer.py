@@ -371,26 +371,31 @@ def train_decentralized(cnn_cfg: CNNConfig, algo_name: str,
                 sbatch = {"x": jnp.asarray(xs), "y": jnp.asarray(ys)}
             lr = lr_fn(t)
             with obs.span("trainer.put"):
-                lr_t = jnp.asarray(lr, jnp.float32)
+                # NumPy scalars of the step's dtypes (not weak-typed, so
+                # the step compiles as for device scalars): the jitted
+                # call transfers each with its arguments, or drops one
+                # its step does not read, and no eager program runs
+                lr_t = np.float32(lr)
                 kw: Dict[str, Any] = {}
                 if algo_name == "gaia":
-                    kw["t0"] = jnp.asarray(
-                        scout.theta if scout else comm.gaia_t0, jnp.float32)
+                    kw["t0"] = np.float32(
+                        scout.theta if scout else comm.gaia_t0)
                 elif algo_name == "fedavg":
-                    kw["iter_local"] = jnp.asarray(
-                        scout.theta if scout else comm.iter_local,
-                        jnp.int32)
+                    kw["iter_local"] = np.int32(
+                        scout.theta if scout else comm.iter_local)
                 elif algo_name == "dgc":
                     epoch = t // steps_per_epoch
                     s = (scout.theta if scout
                          else warmup_sparsity(epoch, comm.dgc_warmup_epochs))
-                    kw["sparsity"] = jnp.asarray(s, jnp.float32)
+                    kw["sparsity"] = np.float32(s)
             if obs.active():
                 # this round's puts: x, y, lr_t, the strategy's scalar
-                # and, in the step call, the round index (4 bytes each)
+                # and the round index (4 bytes each); all but the batch
+                # are host scalars, which the step call itself transfers
                 obs.count("h2d_puts", 4 + len(kw))
                 obs.count("h2d_bytes", sbatch["x"].nbytes +
                           sbatch["y"].nbytes + 4 * (2 + len(kw)))
+                obs.count("host_scalars", 2 + len(kw))
             if pending:
                 # dispatched while the previous round's scalars are unread
                 overlapped += 1
@@ -399,7 +404,7 @@ def train_decentralized(cnn_cfg: CNNConfig, algo_name: str,
             t_step = time.perf_counter()
             with obs.span("trainer.dispatch"):
                 state, metrics = algo.step(state, sbatch, lr_t,
-                                           jnp.asarray(t, jnp.int32), **kw)
+                                           np.int32(t), **kw)
             step_s.append(time.perf_counter() - t_step)
             # the scalars' copies queue behind this step, ahead of the
             # next, and have landed by the time they are read

@@ -1,15 +1,20 @@
 """The trainer's pipelined loop (round t dispatched before round t - 1's
 scalars are read) against a plain lock-step loop written here: every
-strategy's results bit for bit, and the rounds the loop ran ahead."""
+strategy's results bit for bit, and the rounds the loop ran ahead.  And
+the scalars each round hands ``algo.step``: host NumPy scalars with the
+avals of ``jnp.asarray``, so that no eager program converts them."""
 import dataclasses
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs.base import CommConfig
 from repro.configs.cnn_zoo import CNN_ZOO
-from repro.core import partition_label_skew, train_decentralized
+from repro.core import partition_label_skew, train_decentralized, trainer
 from repro.core.algorithms.base import tree_size
 from repro.core.algorithms.dgc import warmup_sparsity
 from repro.core.trainer import GOSSIP_ALGOS, make_algorithm, make_cnn_fns
@@ -113,3 +118,50 @@ def test_pipelined_loop_matches_lock_step(task, algo_name, scout):
     assert r.extras["ledger"] == want["ledger"]
     assert r.extras.get("staleness_curve", []) == want["staleness_curve"]
     assert len(r.extras["step_s"]) == STEPS
+
+
+#: the dtype that each scalar argument of ``algo.step`` has on the chip
+SCALAR_DTYPES = {"lr": jnp.float32, "step_idx": jnp.int32,
+                 "t0": jnp.float32, "iter_local": jnp.int32,
+                 "sparsity": jnp.float32}
+STRATEGY_SCALAR = {"bsp": (), "gaia": ("t0",), "fedavg": ("iter_local",),
+                   "dgc": ("sparsity",), "dpsgd": (), "adpsgd": ()}
+
+
+@pytest.mark.parametrize("algo_name", list(STRATEGY_SCALAR))
+def test_round_scalars_reach_the_step_as_host_values(task, algo_name):
+    parts, val = task
+    calls, make = [], trainer.make_algorithm
+
+    def tapped(*a, **k):
+        algo = make(*a, **k)
+        step = algo.step
+
+        def call(state, batch, lr, step_idx, **kw):
+            calls.append(dict(kw, lr=lr, step_idx=step_idx))
+            # nothing the dispatch does may fetch from the device: the
+            # gossip steps' int(step_idx) reads a host value.  (The CPU
+            # backend lets any fetch through; a chip refuses it here)
+            with jax.transfer_guard_device_to_host("disallow"):
+                return step(state, batch, lr, step_idx, **kw)
+
+        algo.step = call
+        return algo
+
+    with mock.patch.object(trainer, "make_algorithm", tapped), \
+            obs.Recorder() as rec:
+        train_decentralized(CFG, algo_name, parts, val, comm=COMM,
+                            steps=STEPS, batch=BATCH, lr=LR,
+                            eval_every=EVAL_EVERY, seed=SEED)
+    # the rounds' calls, then the lowering that counts Mosaic calls
+    assert len(calls) == STEPS + 1
+    want = {"lr", "step_idx", *STRATEGY_SCALAR[algo_name]}
+    for t, args in enumerate(calls[:STEPS]):
+        assert set(args) == want
+        assert args["step_idx"] == t and args["lr"] == np.float32(LR)
+        for name, v in args.items():
+            assert isinstance(v, np.generic), (name, type(v))
+            assert jax.typeof(v) == jax.typeof(
+                jnp.asarray(v, SCALAR_DTYPES[name])), name
+            assert not jax.typeof(v).weak_type and np.shape(v) == ()
+    assert rec.summary()["counts"]["host_scalars"] == [len(want)] * STEPS
